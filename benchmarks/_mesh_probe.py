@@ -1,10 +1,13 @@
 import os
+# 512 virtual host devices, on the CPU by design: this probe never holds
+# an accelerator, even on a machine that has one
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 
 """Subprocess helper: compile merge schedules / train steps on the
 production meshes and print collective byte accounting as JSON.
 (Separate process because jax locks the device count at first init —
-benchmarks.run itself stays on the single real CPU device.)
+benchmarks.run itself stays on its own devices.)
 """
 
 import argparse  # noqa: E402

@@ -22,26 +22,27 @@ import subprocess
 import sys
 
 
-def _probe(probe_args):
+def probe(probe_args):
+    """Run ``benchmarks._mesh_probe`` in a child pinned to the CPU (its 512
+    virtual devices); a failed child raises, so the caller fails too."""
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
     env["PYTHONPATH"] = "src"
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run(
         [sys.executable, "-m", "benchmarks._mesh_probe"] + probe_args,
         capture_output=True, text=True, env=env, timeout=900,
     )
     if out.returncode != 0:
-        raise RuntimeError(out.stderr[-200:])
+        raise RuntimeError(
+            f"_mesh_probe {' '.join(probe_args)} failed:\n{out.stderr[-2000:]}")
     return json.loads(out.stdout.strip().splitlines()[-1])
 
 
 def run(payload_mb: float = 64.0):
     results = []
-    try:
-        rec = _probe(["--probe", "merge", "--schedule", "two_phase",
-                      "--payload-mb", str(payload_mb)])
-    except RuntimeError as e:
-        return [("fig10_comm_ratio", 0.0, f"ERROR:{e}")]
+    rec = probe(["--probe", "merge", "--schedule", "two_phase",
+                 "--payload-mb", str(payload_mb)])
     merge_dcn = rec["dcn_bytes_per_device"]
     # baseline: the same payload synchronizes cross-pod EVERY step
     for k in [10, 20, 50, 100, 200]:
@@ -53,14 +54,10 @@ def run(payload_mb: float = 64.0):
         ))
 
     # --placement routed vs GSPMD gather: per-step sparse exchange bytes
-    try:
-        sparse = {
-            p: _probe(["--probe", "sparse", "--placement", p])
-            for p in ("gather", "routed")
-        }
-    except RuntimeError as e:
-        results.append(("fig10_sparse", 0.0, f"ERROR:{e}"))
-        return results
+    sparse = {
+        p: probe(["--probe", "sparse", "--placement", p])
+        for p in ("gather", "routed")
+    }
     for p, rec in sparse.items():
         results.append((
             f"fig10_sparse_{p}", 0.0,

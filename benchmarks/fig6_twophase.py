@@ -10,30 +10,18 @@ devices).
 
 from __future__ import annotations
 
-import json
-import os
-import subprocess
-import sys
 import time
+
+from benchmarks.fig10_comm_ratio import probe
 
 
 def run(payload_mb: float = 64.0):
     results = []
-    env = dict(os.environ)
-    env.pop("XLA_FLAGS", None)
-    env["PYTHONPATH"] = "src"
     base = None
     for schedule in ["flat", "two_phase", "bf16", "int8_ef"]:
         t0 = time.perf_counter()
-        out = subprocess.run(
-            [sys.executable, "-m", "benchmarks._mesh_probe", "--probe", "merge",
-             "--schedule", schedule, "--payload-mb", str(payload_mb)],
-            capture_output=True, text=True, env=env, timeout=900,
-        )
-        if out.returncode != 0:
-            results.append((f"fig6_merge_{schedule}", 0.0, f"ERROR:{out.stderr[-200:]}"))
-            continue
-        rec = json.loads(out.stdout.strip().splitlines()[-1])
+        rec = probe(["--probe", "merge", "--schedule", schedule,
+                     "--payload-mb", str(payload_mb)])
         us = (time.perf_counter() - t0) * 1e6
         dcn = rec["dcn_bytes_per_device"]
         if schedule == "flat":

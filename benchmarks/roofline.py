@@ -180,8 +180,6 @@ def _cost_analysis(compiled) -> Dict[str, float]:
         cost = compiled.cost_analysis()
     except Exception:
         return {}
-    if isinstance(cost, (list, tuple)):     # older jax returns [dict]
-        cost = cost[0] if cost else {}
     return {
         "flops": float(cost.get("flops", 0.0)),
         "bytes_accessed": float(cost.get("bytes accessed", 0.0)),

@@ -71,6 +71,7 @@ import jax.numpy as jnp
 
 from repro.core import routed_embedding as routed
 from repro.core.sparse_optim import SparseAdagrad
+from repro.launch.mesh import _make_mesh
 
 
 # --------------------------------------------------------------- working set
@@ -405,7 +406,7 @@ def make_backend(
         return GatherBackend(fused=fused, staged=staged)
     if placement == "routed":
         if mesh is None:
-            mesh = jax.make_mesh((jax.device_count(),), ("data",))
+            mesh = _make_mesh((jax.device_count(),), ("data",))
         return RoutedBackend(mesh, **kwargs)
     if placement == "cached":
         from repro.core.cache_tier import CachedBackend

@@ -655,9 +655,10 @@ class EmbeddingEngine:
         ``working`` — its gradient is exactly the row_grads to scatter back).
 
         ``fused=True`` runs the gather+bag as ONE Pallas kernel pass over the
-        VMEM-resident working set (``kernels.ops.embedding_bag_working``);
-        both branches share the same reference expression, so the fused path
-        is bit-identical — forward and gradient — to the unfused one.
+        working set (``kernels.ops.embedding_bag_working``); both branches
+        share the same reference expression for the gradient.  The forward
+        is bit-identical to the unfused one in interpret mode and equal up
+        to f32 reassociation on TPU.
         """
         from repro.kernels import ops, ref
 

@@ -32,7 +32,6 @@ from typing import Tuple
 
 import jax
 import jax.numpy as jnp
-from jax.experimental.shard_map import shard_map
 from jax.sharding import PartitionSpec as P
 
 
@@ -128,17 +127,17 @@ def make_routed_pull_push(
 
     table_spec = P(axes, None)
     ids_spec = P(axes)
-    pull = shard_map(
+    pull = jax.shard_map(
         pull_body, mesh=mesh,
         in_specs=(table_spec, ids_spec),
         out_specs=(P(axes, None), ids_spec, P(axes)),
-        check_rep=False,
+        check_vma=False,
     )
-    push = shard_map(
+    push = jax.shard_map(
         push_body, mesh=mesh,
         in_specs=(table_spec, table_spec, ids_spec, P(axes, None), P(), P()),
         out_specs=(table_spec, table_spec, P(axes)),
-        check_rep=False,
+        check_vma=False,
     )
     return pull, push
 

@@ -48,6 +48,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.sparse_adagrad import LANES   # buckets per DMA'd row
+
 EMPTY = -1  # bucket key for never-occupied buckets
 
 
@@ -151,32 +153,94 @@ def _mix_scalar(u, hmask):
     return (x & jnp.uint32(hmask)).astype(jnp.int32)
 
 
-def _lookup_kernel(uids_ref, key_ref, slot_ref, suid_ref, out_ref, *, hmask):
-    """One working-set id per grid step: probe from the home bucket until
-    the key or an EMPTY bucket appears; a found entry resolves to its slot
-    only if still live (``slot_uid[slot] == key``) — a stale hit is a miss
-    and the probe stops (at most one bucket per key)."""
-    u = uids_ref[pl.program_id(0)]
-    base = _mix_scalar(u, hmask)
+def _lookup_kernel(uids_ref, key_hbm, slot_hbm, suid_hbm, out_hbm,
+                   kbuf, sbuf, xk, xs, cand, sem, *, hmask):
+    """Probe ``tile`` working-set ids.  The map stays in HBM as rows of
+    128 buckets; each id's home row is DMA'd into SMEM (all ids of the
+    tile in flight at once), the chain is walked there (rows past the home
+    row are fetched on demand), and a found slot resolves only if still
+    live (``slot_uid[slot] == key``) — a stale hit is a miss and the probe
+    stops (at most one bucket per key).  One tile is 128 ids, written
+    back as one 128-lane output row."""
+    tile = LANES
+    base = pl.program_id(0) * tile
 
-    def cond(carry):
-        return carry[0] == 0
+    def row_copy(src, row, dst):
+        return pltpu.make_async_copy(src.at[pl.ds(row, 1)], dst, sem)
 
-    def body(carry):
-        _, off, slot = carry
-        b = (base + off) & hmask
-        kb = key_ref[b, 0]
-        s = slot_ref[b, 0]
-        live = (kb == u) & (suid_ref[s, 0] == u)
-        done = (kb == u) | (kb == EMPTY)
-        slot = jnp.where(live, s, slot)
-        return done.astype(jnp.int32), off + 1, slot
+    def home(k):
+        return _mix_scalar(uids_ref[base + k], hmask)
 
-    zero = jnp.zeros((), jnp.int32)
-    _, _, slot = jax.lax.while_loop(
-        cond, body, (zero, zero, jnp.full((), -1, jnp.int32))
-    )
-    out_ref[0, 0] = slot
+    def home_copies(k):
+        row = home(k) // LANES
+        return (row_copy(key_hbm, row, kbuf.at[pl.ds(k, 1)]),
+                row_copy(slot_hbm, row, sbuf.at[pl.ds(k, 1)]))
+
+    def for_tile(body):
+        jax.lax.fori_loop(0, tile, lambda k, c: (body(k), c)[1], 0)
+
+    for_tile(lambda k: [c.start() for c in home_copies(k)])
+    for_tile(lambda k: [c.wait() for c in home_copies(k)])
+
+    def walk(k):
+        u = uids_ref[base + k]
+        b0 = home(k)
+        row0 = b0 // LANES
+
+        def body(carry):
+            off, xrow, _, _ = carry
+            b = (b0 + off) & hmask
+            row, lane = b // LANES, b % LANES
+            in_home = row == row0
+
+            @pl.when(jnp.logical_and(jnp.logical_not(in_home), row != xrow))
+            def _fetch():
+                cps = (row_copy(key_hbm, row, xk), row_copy(slot_hbm, row, xs))
+                for c in cps:
+                    c.start()
+                for c in cps:
+                    c.wait()
+
+            kb = jnp.where(in_home, kbuf[k, lane], xk[0, lane])
+            s = jnp.where(in_home, sbuf[k, lane], xs[0, lane])
+            done = (kb == u) | (kb == EMPTY)
+            return (off + 1, jnp.where(in_home, xrow, row),
+                    done.astype(jnp.int32), jnp.where(kb == u, s, -1))
+
+        neg = jnp.full((), -1, jnp.int32)
+        zero = jnp.zeros((), jnp.int32)
+        _, _, _, s = jax.lax.while_loop(
+            lambda c: c[2] == 0, body, (zero, neg, zero, neg))
+        cand[k] = s
+
+    for_tile(walk)
+
+    # liveness: slot_uid[s] == key, one row DMA per candidate (into kbuf,
+    # whose home rows are no longer needed)
+    def live_copy(k):
+        return row_copy(suid_hbm, cand[k] // LANES, kbuf.at[pl.ds(k, 1)])
+
+    for_tile(lambda k: pl.when(cand[k] >= 0)(lambda: live_copy(k).start()))
+    for_tile(lambda k: pl.when(cand[k] >= 0)(lambda: live_copy(k).wait()))
+
+    def resolve(k):
+        s = cand[k]
+        live = (s >= 0) & (kbuf[k, s % LANES] == uids_ref[base + k])
+        xk[0, k] = jnp.where(live, s, -1)
+
+    for_tile(resolve)
+    out = pltpu.make_async_copy(xk, out_hbm.at[pl.ds(pl.program_id(0), 1)],
+                                sem)
+    out.start()
+    out.wait()
+
+
+def _lane_rows(x: jnp.ndarray, fill: int) -> jnp.ndarray:
+    """(N,) -> (ceil(N/128), 128): a free view when 128 divides N."""
+    n = pl.cdiv(x.shape[0], LANES) * LANES
+    if n != x.shape[0]:
+        x = jnp.pad(x, (0, n - x.shape[0]), constant_values=fill)
+    return x.reshape(n // LANES, LANES)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -185,19 +249,27 @@ def hash_lookup_pallas(key_tab, slot_tab, slot_uid, uids, interpret=False):
     output feeds the fused cached gather/scatter index streams."""
     H = key_tab.shape[0]
     K = uids.shape[0]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=(K,),
-        in_specs=[
-            pl.BlockSpec((H, 1), lambda i, uids: (0, 0)),
-            pl.BlockSpec((H, 1), lambda i, uids: (0, 0)),
-            pl.BlockSpec((slot_uid.shape[0], 1), lambda i, uids: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1), lambda i, uids: (i, 0)),
-    )
+    kp = pl.cdiv(K, LANES) * LANES
+    if kp != K:
+        uids = jnp.pad(uids, (0, kp - K))
+    anyspec = pl.BlockSpec(memory_space=pl.ANY)
     out = pl.pallas_call(
         functools.partial(_lookup_kernel, hmask=H - 1),
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((K, 1), jnp.int32),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(kp // LANES,),
+            in_specs=[anyspec, anyspec, anyspec],
+            out_specs=anyspec,
+            scratch_shapes=[
+                pltpu.SMEM((LANES, LANES), jnp.int32),  # home key rows
+                pltpu.SMEM((LANES, LANES), jnp.int32),  # home slot rows
+                pltpu.SMEM((1, LANES), jnp.int32),      # far key row / result
+                pltpu.SMEM((1, LANES), jnp.int32),      # far slot row
+                pltpu.SMEM((LANES,), jnp.int32),        # found slot per id
+                pltpu.SemaphoreType.DMA(()),
+            ],
+        ),
+        out_shape=jax.ShapeDtypeStruct((kp // LANES, LANES), jnp.int32),
         interpret=interpret,
-    )(uids, key_tab[:, None], slot_tab[:, None], slot_uid[:, None])
-    return out[:, 0]
+    )(uids, _lane_rows(key_tab, EMPTY), _lane_rows(slot_tab, 0),
+      _lane_rows(slot_uid, -1))
+    return out.reshape(-1)[:K]

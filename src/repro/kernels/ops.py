@@ -1,10 +1,9 @@
 """jit'd public wrappers for the Pallas kernels.
 
-On TPU the Pallas path compiles natively; everywhere else (this CPU
-container) the wrappers run the kernels in interpret mode when
-``REPRO_KERNEL_INTERPRET=1`` (tests) or fall back to the jnp oracle —
-so the framework is runnable on any backend while keeping the TPU kernel
-as the deployment path.
+On a TPU backend the kernels always compile through Mosaic — nothing,
+``REPRO_KERNEL_INTERPRET`` included, can put them in interpret mode there.
+On other backends (CPU tests) the wrappers run the kernels in interpret
+mode when ``REPRO_KERNEL_INTERPRET=1`` or call the jnp oracle otherwise.
 
 ``kernel_mode()`` is the dispatch truth ("pallas" / "interpret" / "ref");
 ``resolve_fused()`` maps the ``TrainerConfig.fused_kernels`` tri-state
@@ -17,11 +16,13 @@ the config axis is portable across backends.
 
 from __future__ import annotations
 
+import functools
 import os
 
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.sharding import PartitionSpec as P
 
 from repro.kernels import ref
 from repro.kernels.dot_interaction import dot_interaction_pallas
@@ -40,15 +41,48 @@ _COMBINERS = ("sum", "mean", "sqrtn")
 
 
 def kernel_mode() -> str:
-    """How fused ops execute here: "pallas" | "interpret" | "ref"."""
-    if os.environ.get("REPRO_KERNEL_INTERPRET") == "1":
-        return "interpret"
+    """How fused ops execute here: "pallas" | "interpret" | "ref".
+
+    A TPU backend is always "pallas": the interpret override only selects
+    how kernels run where there is no chip."""
     if jax.default_backend() == "tpu":
         return "pallas"
+    if os.environ.get("REPRO_KERNEL_INTERPRET") == "1":
+        return "interpret"
     return "ref"
 
 
-_mode = kernel_mode  # internal alias, kept for existing callers
+def traced_on(mesh, fn):
+    """``fn`` traced with ``mesh`` as the ambient mesh, so that the kernels
+    it calls run whole on each device (``per_device``).  A no-op for no
+    mesh or a one-device mesh."""
+    if mesh is None or mesh.size == 1:
+        return fn
+
+    @functools.wraps(fn)
+    def body(*args, **kwargs):
+        with jax.sharding.use_abstract_mesh(mesh.abstract_mesh):
+            return fn(*args, **kwargs)
+
+    return body
+
+
+def per_device(fn):
+    """XLA cannot partition a Mosaic kernel: in a program over a
+    multi-device mesh (``traced_on``), run ``fn`` whole on every device,
+    its inputs and outputs replicated."""
+    mesh = jax.sharding.get_abstract_mesh()
+    if mesh.empty or mesh.size == 1:
+        return fn
+    return jax.shard_map(fn, mesh=mesh, in_specs=P(), out_specs=P(),
+                         check_vma=False)
+
+
+def _run(kernel, mode, *args, **static):
+    """``kernel(*args)`` compiled (mode "pallas") or interpreted, whole on
+    each device."""
+    return per_device(functools.partial(
+        kernel, interpret=(mode == "interpret"), **static))(*args)
 
 
 def fused_default() -> bool:
@@ -67,13 +101,11 @@ def resolve_fused(flag) -> bool:
 
 
 def embedding_bag(working, inv, seg, weights, num_bags, **kw):
-    mode = _mode()
+    mode = kernel_mode()
     if mode == "ref":
         return ref.embedding_bag_ref(working, inv, seg, weights, num_bags)
-    return embedding_bag_pallas(
-        working, inv, seg, weights, num_bags,
-        interpret=(mode == "interpret"), **kw,
-    )
+    return _run(embedding_bag_pallas, mode, working, inv, seg, weights,
+                num_bags=num_bags, **kw)
 
 
 def embedding_bag_working(working, inv, seg, weights, num_bags,
@@ -89,18 +121,17 @@ def embedding_bag_working(working, inv, seg, weights, num_bags,
     """
     if combiner not in _COMBINERS:
         raise ValueError(f"unknown combiner: {combiner!r}")
-    mode = _mode()
+    mode = kernel_mode()
     if mode == "ref":
         return ref.embedding_bag_combiner_ref(
             working, inv, seg, weights, num_bags, combiner)
-    interpret = mode == "interpret"
 
     # inv/seg are primal args (NOT closed over — closures would leak tracers
     # under vmap/grad) with float0 cotangents, as integer inputs require.
     @jax.custom_vjp
     def bag(wk, inv_, seg_, w):
-        out = embedding_bag_pallas(wk, inv_, seg_, w, num_bags,
-                                   interpret=interpret)
+        out = _run(embedding_bag_pallas, mode, wk, inv_, seg_, w,
+                   num_bags=num_bags)
         if combiner != "sum":
             denom = ref.bag_combiner_denom_ref(seg_, num_bags, combiner,
                                                wk.dtype)
@@ -126,7 +157,7 @@ def embedding_bag_working(working, inv, seg, weights, num_bags,
 
 
 def dot_interaction(feats, **kw):
-    mode = _mode()
+    mode = kernel_mode()
     if mode == "ref":
         return ref.dot_interaction_ref(feats)
     return dot_interaction_pallas(feats, interpret=(mode == "interpret"), **kw)
@@ -146,7 +177,7 @@ def fused_adam(p, g, m, v, v_hat, lr=1e-3, b1=None, b2=None, **kw):
         db1, db2 = adam_defaults()
         b1 = db1 if b1 is None else b1
         b2 = db2 if b2 is None else b2
-    mode = _mode()
+    mode = kernel_mode()
     if mode == "ref":
         return ref.fused_adam_ref(p, g, m, v, v_hat, lr, b1, b2)
     return fused_adam_pallas(
@@ -156,7 +187,7 @@ def fused_adam(p, g, m, v, v_hat, lr=1e-3, b1=None, b2=None, **kw):
 
 
 def sparse_adagrad(rows, accum, grads, lr=0.05, eps=1e-10, **kw):
-    mode = _mode()
+    mode = kernel_mode()
     if mode == "ref":
         return ref.sparse_adagrad_ref(rows, accum, grads, lr, eps)
     return sparse_adagrad_pallas(
@@ -174,11 +205,11 @@ def sparse_adagrad_apply(table, accum, uids, grads, *, lr, eps):
     """
     delta, g2 = adagrad_row_updates(accum[uids], grads, table.dtype,
                                     lr=lr, eps=eps)
-    mode = _mode()
+    mode = kernel_mode()
     if mode == "ref":
         return ref.sparse_adagrad_apply_ref(table, accum, uids, delta, g2)
-    return sparse_adagrad_apply_pallas(
-        table, accum, uids, delta, g2, interpret=(mode == "interpret"))
+    return _run(sparse_adagrad_apply_pallas, mode,
+                table, accum, uids, delta, g2)
 
 
 def hash_lookup(key_tab, slot_tab, slot_uid, uids):
@@ -189,21 +220,19 @@ def hash_lookup(key_tab, slot_tab, slot_uid, uids):
     over identical map contents (map maintenance is shared trace-level
     jnp), so the dispatch mode can never change a hit into a miss.
     """
-    mode = _mode()
+    mode = kernel_mode()
     if mode == "ref":
         return ref.hash_lookup_ref(key_tab, slot_tab, slot_uid, uids)
-    return hash_lookup_pallas(
-        key_tab, slot_tab, slot_uid, uids, interpret=(mode == "interpret"))
+    return _run(hash_lookup_pallas, mode, key_tab, slot_tab, slot_uid, uids)
 
 
 def gather_rows_cached(cache_rows, slots):
     """Fused cached pull: out[i] = cache_rows[slots[i]], with the
     hash-probe output as the kernel's index stream."""
-    mode = _mode()
+    mode = kernel_mode()
     if mode == "ref":
         return ref.gather_rows_cached_ref(cache_rows, slots)
-    return gather_rows_cached_pallas(
-        cache_rows, slots, interpret=(mode == "interpret"))
+    return _run(gather_rows_cached_pallas, mode, cache_rows, slots)
 
 
 def sparse_adagrad_cached_apply(cache_rows, cache_accum, slots, grads,
@@ -213,10 +242,9 @@ def sparse_adagrad_cached_apply(cache_rows, cache_accum, slots, grads,
     accum_rows = gather_rows_cached(cache_accum, slots)
     delta, g2 = adagrad_row_updates(accum_rows, grads, cache_rows.dtype,
                                     lr=lr, eps=eps)
-    mode = _mode()
+    mode = kernel_mode()
     if mode == "ref":
         return ref.sparse_adagrad_apply_ref(
             cache_rows, cache_accum, slots, delta, g2)
-    return sparse_adagrad_cached_apply_pallas(
-        cache_rows, cache_accum, slots, delta, g2,
-        interpret=(mode == "interpret"))
+    return _run(sparse_adagrad_cached_apply_pallas, mode,
+                cache_rows, cache_accum, slots, delta, g2)

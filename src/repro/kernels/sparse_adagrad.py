@@ -11,28 +11,26 @@ geometry works).
 
 ``sparse_adagrad_apply_pallas`` is the *scatter* push used by the real
 hot path: it applies per-row (delta, g2) updates directly into the full
-(rows, dim) table/accumulator via scalar-prefetched row indices, aliasing
-the table and accumulator buffers so no intermediate updated-rows array is
-materialized.  The AdaGrad arithmetic itself is computed ONCE outside the
-kernel by :func:`adagrad_row_updates` (shared with the unfused
+(rows, dim) table/accumulator, which stay in HBM and are aliased to the
+outputs, so no intermediate updated-rows array is materialized.  Each grid
+step DMAs one tile of indexed rows into VMEM, adds, and DMAs them back.
+The AdaGrad arithmetic itself is computed ONCE outside the kernel by
+:func:`adagrad_row_updates` (shared with the unfused
 ``SparseAdagrad.apply_rows``) and the kernel body is pure data movement
 (``add`` of two loads) — that is what makes the fused push bit-identical
 to the unfused scatter on every backend: LLVM/XLA cannot re-contract a
 mul+add into an FMA when the kernel never sees the mul.
 
-The grid walks the working set in REVERSE: ``pull_working_set`` pads
-``uids`` with copies of the minimum real id at the END of the vector, so
-reversed order makes the pad rows (zero grads → bit-preserving writes)
-execute first and the single real visit to the duplicated row last —
-safe against stale-read/overwrite races when the TPU pipeline revisits
-the same table row.
+``pull_working_set`` pads ``uids`` with copies of the first (minimum) id,
+and pads carry zero gradient, so the kernel does not write a row that
+repeats entry 0: the rows it writes are distinct, and no two of its DMAs
+race on one row.
 
 ``sparse_adagrad_cached_apply_pallas`` / ``gather_rows_cached_pallas``
-are the cache-tier variants: the id→slot hash-probe output
-(``kernels.hash_map.hash_lookup_pallas``) is the kernel's scalar-prefetch
-index stream (``row = slots[i]``), so the cached pull/push do one indexed
-pass over the (slots, dim) cache instead of materializing slot-translated
-row gathers around the kernel.
+are the cache-tier uses of the same kernels: the id→slot hash-probe output
+(``kernels.hash_map.hash_lookup_pallas``) is the index stream, so the
+cached pull/push do one indexed pass over the (slots, dim) cache instead of
+materializing slot-translated row gathers around the kernel.
 """
 
 from __future__ import annotations
@@ -98,101 +96,168 @@ def sparse_adagrad_pallas(
     )(rows, accum, grads)
 
 
-def _apply_kernel(uids_ref, t_ref, a_ref, d_ref, g2_ref, nt_ref, na_ref):
-    # Pure data movement: both adds combine two LOADS (delta/g2 precomputed
-    # outside) — contraction-proof, hence bit-identical to the jnp scatter.
-    nt_ref[...] = t_ref[...] + d_ref[...]
-    na_ref[...] = a_ref[...] + g2_ref[...]
+# ---------------------------------------------------------------------------
+# index-stream row kernels: the table stays in HBM, rows move by DMA
+# ---------------------------------------------------------------------------
+
+LANES = 128
+
+
+def lane_pad(x: jnp.ndarray) -> jnp.ndarray:
+    """(N, D) -> (N, Dp) with Dp the next multiple of 128 lanes.
+
+    Mosaic slices an HBM array only in whole lane tiles, so a row narrower
+    than 128 lanes (baidu-ctr's D=64) is DMA'd from a lane-padded copy.
+    The padded lanes are sliced off again by the callers."""
+    d = x.shape[1]
+    dp = pl.cdiv(d, LANES) * LANES
+    return x if dp == d else jnp.pad(x, ((0, 0), (0, dp - d)))
+
+
+def row_tile(n: int, target: int = 128) -> int:
+    """Rows per grid step: whole 8-row sublane tiles, at most ``target``."""
+    return min(target, pl.cdiv(n, 8) * 8)
+
+
+def _pad_to(x: jnp.ndarray, n: int, value=0) -> jnp.ndarray:
+    if x.shape[0] == n:
+        return x
+    widths = ((0, n - x.shape[0]),) + ((0, 0),) * (x.ndim - 1)
+    return jnp.pad(x, widths, constant_values=value)
+
+
+def _fori(n: int, body):
+    jax.lax.fori_loop(0, n, lambda k, c: (body(k), c)[1], 0)
+
+
+def _gather_kernel(idx_ref, src_hbm, out_ref, sem, *, tile):
+    base = pl.program_id(0) * tile
+
+    def copy(k):
+        return pltpu.make_async_copy(
+            src_hbm.at[pl.ds(idx_ref[base + k], 1)],
+            out_ref.at[pl.ds(k, 1)], sem)
+
+    _fori(tile, lambda k: copy(k).start())   # every row of the tile in flight
+    _fori(tile, lambda k: copy(k).wait())
+
+
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def gather_rows_pallas(src: jnp.ndarray, idx: jnp.ndarray,
+                       interpret: bool = False) -> jnp.ndarray:
+    """out[i] = src[idx[i]]: (src rows, D) in HBM, one row DMA per index
+    into a (tile, Dp) VMEM output block."""
+    K, D = idx.shape[0], src.shape[1]
+    tile = row_tile(K)
+    kp = pl.cdiv(K, tile) * tile
+    srcp = lane_pad(src)
+    dp = srcp.shape[1]
+    out = pl.pallas_call(
+        functools.partial(_gather_kernel, tile=tile),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(kp // tile,),
+            in_specs=[pl.BlockSpec(memory_space=pl.ANY)],
+            out_specs=pl.BlockSpec((tile, dp), lambda i, idx: (i, 0)),
+            scratch_shapes=[pltpu.SemaphoreType.DMA(())],
+        ),
+        out_shape=jax.ShapeDtypeStruct((kp, dp), src.dtype),
+        interpret=interpret,
+    )(_pad_to(idx, kp), srcp)
+    return out[:K, :D]
+
+
+def _apply_kernel(idx_ref, d_ref, g2_ref, t_in, a_in, t_ref, a_ref,
+                  tbuf, abuf, sem, *, tile):
+    """Read the tile's rows, add (delta, g2), write them back.
+
+    Pure data movement plus two adds of LOADS (delta/g2 precomputed
+    outside), so the result is bit-identical to the jnp scatter-add.  A
+    row that repeats entry 0's row is a capacity pad (zero update, see
+    ``sparse_adagrad_apply_pallas``) and is not written: every written row
+    is then distinct, so no two DMAs of one call ever race on a row."""
+    del t_in, a_in           # aliased with t_ref / a_ref
+    base = pl.program_id(0) * tile
+    first = idx_ref[0]
+
+    def pairs(k):            # (HBM row, VMEM row) of table and accumulator
+        r = idx_ref[base + k]
+        return ((t_ref.at[pl.ds(r, 1)], tbuf.at[pl.ds(k, 1)]),
+                (a_ref.at[pl.ds(r, 1)], abuf.at[pl.ds(k, 1)]))
+
+    def read(k):
+        return [pltpu.make_async_copy(h, v, sem) for h, v in pairs(k)]
+
+    def write(k):
+        return [pltpu.make_async_copy(v, h, sem) for h, v in pairs(k)]
+
+    def start(copies):
+        for c in copies:
+            c.start()
+
+    def wait(copies):
+        for c in copies:
+            c.wait()
+
+    def unless_pad(k, fn):
+        pl.when((base + k == 0) | (idx_ref[base + k] != first))(fn)
+
+    _fori(tile, lambda k: start(read(k)))
+    _fori(tile, lambda k: wait(read(k)))
+    tbuf[...] = tbuf[...] + d_ref[...]
+    abuf[...] = abuf[...] + g2_ref[...]
+    _fori(tile, lambda k: unless_pad(k, lambda: start(write(k))))
+    _fori(tile, lambda k: unless_pad(k, lambda: wait(write(k))))
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def sparse_adagrad_apply_pallas(
     table: jnp.ndarray,   # (R, D) full table
     accum: jnp.ndarray,   # (R, D) f32 accumulator
-    uids: jnp.ndarray,    # (cap,) row ids, pads (= min real id) at the END
+    uids: jnp.ndarray,    # (cap,) row ids; repeats of uids[0] are pads
     delta: jnp.ndarray,   # (cap, D) table-dtype update, from adagrad_row_updates
     g2: jnp.ndarray,      # (cap, D) f32 squared grads
     interpret: bool = False,
 ):
-    cap = uids.shape[0]
-    D = table.shape[1]
-    row = lambda i, uids: (uids[cap - 1 - i], 0)     # reversed: pads first
-    seq = lambda i, uids: (cap - 1 - i, 0)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=(cap,),
-        in_specs=[pl.BlockSpec((1, D), row), pl.BlockSpec((1, D), row),
-                  pl.BlockSpec((1, D), seq), pl.BlockSpec((1, D), seq)],
-        out_specs=[pl.BlockSpec((1, D), row), pl.BlockSpec((1, D), row)],
-    )
-    return pl.pallas_call(
-        _apply_kernel, grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(table.shape, table.dtype),
-                   jax.ShapeDtypeStruct(accum.shape, jnp.float32)],
-        # alias indices count the scalar-prefetch arg: uids=0, table=1, accum=2
-        input_output_aliases={1: 0, 2: 1},
+    """table[uids] += delta, accum[uids] += g2, in place in HBM.
+
+    Index contract (``pull_working_set``'s and the cache tier's): the only
+    repeated rows are pads that repeat ``uids[0]`` and carry a zero
+    update, so skipping their writes equals the jnp scatter-add."""
+    cap, D = uids.shape[0], table.shape[1]
+    tile = row_tile(cap)
+    kp = pl.cdiv(cap, tile) * tile
+    tp, ap = lane_pad(table), lane_pad(accum)
+    dp = tp.shape[1]
+    uids = _pad_to(uids, kp, value=uids[0])
+    delta = _pad_to(lane_pad(delta), kp)
+    g2 = _pad_to(lane_pad(g2), kp)
+    blk = pl.BlockSpec((tile, dp), lambda i, idx: (i, 0))
+    anyspec = pl.BlockSpec(memory_space=pl.ANY)
+    new_t, new_a = pl.pallas_call(
+        functools.partial(_apply_kernel, tile=tile),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1, grid=(kp // tile,),
+            in_specs=[blk, blk, anyspec, anyspec],
+            out_specs=[anyspec, anyspec],
+            scratch_shapes=[pltpu.VMEM((tile, dp), table.dtype),
+                            pltpu.VMEM((tile, dp), jnp.float32),
+                            pltpu.SemaphoreType.DMA(())],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(tp.shape, table.dtype),
+                   jax.ShapeDtypeStruct(ap.shape, jnp.float32)],
+        # alias indices count the scalar-prefetch arg: uids=0, table=3, accum=4
+        input_output_aliases={3: 0, 4: 1},
         interpret=interpret,
-    )(uids, table, accum, delta, g2)
+    )(uids, delta, g2, tp, ap)
+    return new_t[:, :D], new_a[:, :D]
 
 
-def _cached_apply_kernel(slots_ref, t_ref, a_ref, d_ref, g2_ref,
-                         nt_ref, na_ref):
-    nt_ref[...] = t_ref[...] + d_ref[...]
-    na_ref[...] = a_ref[...] + g2_ref[...]
+# The cache tier's push is the same kernel over (cache_rows, cache_accum),
+# driven by the hash probe's slot stream: pads share the first id's slot.
+sparse_adagrad_cached_apply_pallas = sparse_adagrad_apply_pallas
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def sparse_adagrad_cached_apply_pallas(
-    cache_rows: jnp.ndarray,   # (slots, D) device cache
-    cache_accum: jnp.ndarray,  # (slots, D) f32
-    slots: jnp.ndarray,        # (cap,) cache slot per working-set id — the
-                               # hash-probe output; pad ids share the first
-                               # real id's slot and carry zero delta/g2
-    delta: jnp.ndarray,        # (cap, D)
-    g2: jnp.ndarray,           # (cap, D)
-    interpret: bool = False,
-):
-    cap = slots.shape[0]
-    D = cache_rows.shape[1]
-    # The hash-probe lookup output IS the index stream: one indexed pass
-    # over the cache, no slot-translated gather materialized.
-    row = lambda i, slots: (slots[cap - 1 - i], 0)
-    seq = lambda i, slots: (cap - 1 - i, 0)
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=(cap,),
-        in_specs=[pl.BlockSpec((1, D), row), pl.BlockSpec((1, D), row),
-                  pl.BlockSpec((1, D), seq), pl.BlockSpec((1, D), seq)],
-        out_specs=[pl.BlockSpec((1, D), row), pl.BlockSpec((1, D), row)],
-    )
-    return pl.pallas_call(
-        _cached_apply_kernel, grid_spec=grid_spec,
-        out_shape=[jax.ShapeDtypeStruct(cache_rows.shape, cache_rows.dtype),
-                   jax.ShapeDtypeStruct(cache_accum.shape, jnp.float32)],
-        input_output_aliases={1: 0, 2: 1},
-        interpret=interpret,
-    )(slots, cache_rows, cache_accum, delta, g2)
-
-
-def _gather_cached_kernel(slots_ref, rows_ref, out_ref):
-    out_ref[...] = rows_ref[...]
-
-
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def gather_rows_cached_pallas(
-    cache_rows: jnp.ndarray,  # (slots, D)
-    slots: jnp.ndarray,       # (cap,) cache slot per working-set id
-    interpret: bool = False,
-):
+def gather_rows_cached_pallas(cache_rows, slots, interpret: bool = False):
     """out[i] = cache_rows[slots[i]] — the fused cached pull, indexed by
     the hash-probe output stream."""
-    cap = slots.shape[0]
-    D = cache_rows.shape[1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1, grid=(cap,),
-        in_specs=[pl.BlockSpec((1, D), lambda i, slots: (slots[i], 0))],
-        out_specs=pl.BlockSpec((1, D), lambda i, slots: (i, 0)),
-    )
-    return pl.pallas_call(
-        _gather_cached_kernel, grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((cap, D), cache_rows.dtype),
-        interpret=interpret,
-    )(slots, cache_rows)
+    return gather_rows_pallas(cache_rows, slots, interpret=interpret)

@@ -1,4 +1,7 @@
 import os
+# compiled on 512 virtual host devices, on the CPU by design: the dry run
+# never holds an accelerator, even on a machine that has one
+os.environ["JAX_PLATFORMS"] = "cpu"
 os.environ["XLA_FLAGS"] = (
     "--xla_force_host_platform_device_count=512 "
     + os.environ.get("REPRO_EXTRA_XLA_FLAGS", "")
@@ -13,8 +16,9 @@ production meshes and record memory / cost / collective analyses.
 Results land in experiments/dryrun/<mesh>/<arch>__<shape>.json; the roofline
 (benchmarks/roofline.py) and EXPERIMENTS.md read from there.
 
-NOTE: the XLA_FLAGS line above MUST execute before any other import (jax
-locks the device count on first init) — do not move it.
+NOTE: the JAX_PLATFORMS / XLA_FLAGS lines above MUST execute before any
+other import (jax locks the platform and device count on first init) — do
+not move them.
 """
 
 import argparse   # noqa: E402

@@ -15,13 +15,11 @@ import jax
 
 
 def _make_mesh(shape, axes) -> jax.sharding.Mesh:
-    """jax.make_mesh across jax versions: ``axis_types`` (and the AxisType
-    enum) only exist on newer releases; all axes here are Auto, which is
-    also the default, so omit the kwarg when unsupported."""
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    if axis_type is None:
-        return jax.make_mesh(shape, axes)
-    return jax.make_mesh(shape, axes, axis_types=(axis_type.Auto,) * len(axes))
+    """The one mesh constructor: every axis Auto.  ``jax.make_mesh`` now
+    defaults to Explicit axes, whose sharded shard_map outputs cannot feed
+    the plain ``jnp.take`` that follows the routed pull."""
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

@@ -53,7 +53,8 @@ pull latency.  ``--merge-delay N`` (DenseTrainer archs only) applies each
 k-step merge's cross-pod average N boundaries late (DCN latency hiding).
 ``--fused-kernels {auto,on,off}`` selects the fused Pallas sparse kernels
 (gather+bag pull, scatter+AdaGrad push, cache-tier indirection variants —
-see docs/kernels.md); bit-identical to the unfused path on every backend.
+see docs/kernels.md); bit-identical to the unfused path except for the
+bag's f32 summation order on TPU.
 
 ``--serve`` co-locates a CTR serving tier with recsys training: a
 ``CTRServer`` (``runtime.serve_ctr``) scores a second request stream
@@ -118,7 +119,8 @@ def build_argparser() -> argparse.ArgumentParser:
     ap.add_argument("--fused-kernels", default="auto",
                     choices=["auto", "on", "off"],
                     help="fused Pallas sparse pull/push + embedding-bag "
-                         "kernels (bit-identical to unfused): auto = on "
+                         "kernels (equal to unfused up to the bag's f32 "
+                         "summation order on TPU): auto = on "
                          "for a real TPU backend, off elsewhere; 'on' off-"
                          "TPU runs interpret under REPRO_KERNEL_INTERPRET=1 "
                          "or the jnp reference otherwise")
@@ -155,28 +157,13 @@ def build_argparser() -> argparse.ArgumentParser:
     return ap
 
 
-def main():
-    args = build_argparser().parse_args()
-    if args.coordinator:
-        import jax
-        jax.distributed.initialize(
-            coordinator_address=args.coordinator,
-            num_processes=args.num_processes,
-            process_id=args.process_id,
-        )
-
-    import numpy as np
-    from repro import configs
+def trainer_config(args):
+    """The ``TrainerConfig`` the parsed launcher flags describe."""
     from repro.core.kstep import KStepConfig
     from repro.core.sparse_optim import SparseAdagradConfig
-    from repro.data import synthetic as S
-    from repro.runtime.factory import build_trainer
-    from repro.runtime.online import fit_online
     from repro.runtime.trainer import TrainerConfig
 
-    spec = configs.get(args.arch)
-    cfg = spec.smoke_cfg if args.smoke else spec.model_cfg
-    tcfg = TrainerConfig(
+    return TrainerConfig(
         n_pod=args.n_pod,
         kstep=KStepConfig(lr=args.lr, k=args.k, merge=args.merge),
         sparse=SparseAdagradConfig(lr=args.sparse_lr, initial_accumulator=0.01),
@@ -190,6 +177,30 @@ def main():
         merge_delay=args.merge_delay,
         ckpt_dir=args.ckpt_dir or None, ckpt_every=args.ckpt_every,
     )
+
+
+def main():
+    args = build_argparser().parse_args()
+    from repro.launch.compile_cache import enable_compile_cache
+
+    enable_compile_cache()
+    if args.coordinator:
+        import jax
+        jax.distributed.initialize(
+            coordinator_address=args.coordinator,
+            num_processes=args.num_processes,
+            process_id=args.process_id,
+        )
+
+    import numpy as np
+    from repro import configs
+    from repro.data import synthetic as S
+    from repro.runtime.factory import build_trainer
+    from repro.runtime.online import fit_online
+
+    spec = configs.get(args.arch)
+    cfg = spec.smoke_cfg if args.smoke else spec.model_cfg
+    tcfg = trainer_config(args)
     t0 = time.perf_counter()
 
     if spec.family == "lm":

@@ -48,6 +48,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import functools
 import os
 import shutil
 import time
@@ -62,6 +63,7 @@ from repro.core.embedding_engine import EmbeddingEngine
 from repro.core.kstep import KStepAdam, KStepConfig, pod_replicate, pod_slice
 from repro.core.prefetch import PrefetchingEngine
 from repro.core.sparse_optim import SparseAdagradConfig
+from repro.kernels import ops
 
 Pytree = Any
 
@@ -443,12 +445,15 @@ class HybridTrainer:
         # stage 2: fwd/bwd on the working set + k-step Adam + push.  The
         # working sets (arg 4) are NOT donated: their int index buffers and
         # capacity-shaped rows can never alias the stage's outputs.
+        # Stages over a multi-device mesh trace on it, so the Pallas
+        # kernels inside run whole on each device.
         train_donate = (0, 1, 2, 3, 6, 7) if donate else ()
+        on_mesh = functools.partial(ops.traced_on, self._state_mesh())
         self._train_local = jax.jit(
-            self._make_train(False), donate_argnums=train_donate
+            on_mesh(self._make_train(False)), donate_argnums=train_donate
         )
         self._train_merge = jax.jit(
-            self._make_train(True), donate_argnums=train_donate
+            on_mesh(self._make_train(True)), donate_argnums=train_donate
         )
         self._prefetcher = (
             PrefetchingEngine(engine, donate=donate) if cfg.prefetch else None
@@ -459,7 +464,8 @@ class HybridTrainer:
         # Nothing is donated — predict must not consume the committed
         # training state (the engine's lookup contract guarantees it also
         # mutates none of it).
-        self._predict_jit = jax.jit(self._predict_traced, donate_argnums=())
+        self._predict_jit = jax.jit(on_mesh(self._predict_traced),
+                                    donate_argnums=())
         # serving-side meters, accumulated host-side per predict — kept
         # fully separate from the training-interval cache stats so
         # interleaved serving never moves sparse_metrics (see
@@ -510,6 +516,12 @@ class HybridTrainer:
     def pod_batch(self, batch):
         return pod_batch(batch, self.n_pod)
 
+    def _state_mesh(self):
+        """The mesh the trainer state lives on: the trainer's own, else the
+        backend's (``RoutedBackend`` builds one), else None."""
+        return self.mesh if self.mesh is not None else getattr(
+            self.engine.backend, "mesh", None)
+
     def _commit_to_mesh(self):
         """Commit the trainer state to the mesh's replicated sharding.
 
@@ -523,8 +535,7 @@ class HybridTrainer:
         The backend's internal mesh counts too: ``RoutedBackend`` builds one
         when none is passed, and its shard_maps stamp that mesh's sharding
         on every output flowing through the train jit."""
-        mesh = self.mesh if self.mesh is not None else getattr(
-            self.engine.backend, "mesh", None)
+        mesh = self._state_mesh()
         if mesh is None:
             return
         rep = jax.sharding.NamedSharding(mesh, jax.sharding.PartitionSpec())

@@ -51,7 +51,7 @@ def test_callback_check_recurses_into_scan():
 
 
 def test_f64_check_catches_widening():
-    with jax.experimental.enable_x64():
+    with jax.enable_x64(True):
         jx = jax.make_jaxpr(lambda x: x * 2.0)(np.float64(1.0))
     assert f64_leaks(jx) != []
 

@@ -6,10 +6,9 @@ unfused jnp expressions on every backend, forward and gradient.  Anything
 weaker would make ``--fused-kernels`` a numerics knob instead of a perf
 knob, and fused-vs-unfused loss curves would silently diverge.
 
-Property tests (hypothesis, with the deterministic fallback shim) sweep
-odd geometries, all combiners, weighted/unweighted bags and drop-row
-traffic; the remaining tests check the backend objects and a short
-end-to-end fit.  The suite runs under ``REPRO_KERNEL_INTERPRET=1`` (set by
+Property tests (hypothesis) sweep odd geometries, all combiners,
+weighted/unweighted bags and drop-row traffic; the remaining tests check
+the backend objects and a short end-to-end fit.  The suite runs under ``REPRO_KERNEL_INTERPRET=1`` (set by
 conftest), so fused ops execute through Pallas interpret mode — the same
 kernel code that compiles on TPU.
 """
@@ -19,10 +18,7 @@ import jax.numpy as jnp
 import numpy as np
 import pytest
 
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:
-    from tests._hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core.cache_tier import CachedBackend
 from repro.core.embedding_backend import GatherBackend, make_backend

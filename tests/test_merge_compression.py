@@ -3,10 +3,7 @@
 import jax
 import jax.numpy as jnp
 import numpy as np
-try:
-    from hypothesis import given, settings, strategies as st
-except ImportError:  # container without hypothesis: deterministic replay
-    from tests._hypothesis_compat import given, settings, strategies as st
+from hypothesis import given, settings, strategies as st
 
 from repro.core import merge as merge_lib
 from repro.core.compression import dequantize_int8, quantize_int8, quantization_residual
