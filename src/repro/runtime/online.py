@@ -32,6 +32,7 @@ from typing import Iterator, Optional, Tuple
 
 import jax
 
+from repro.runtime import spans
 from repro.runtime.metrics import StreamingAUC
 from repro.runtime.trainer import history_record
 
@@ -76,16 +77,18 @@ def fit_online(
              else contextlib.nullcontext)
 
     def _record():
-        rec = history_record(trainer, loss, t0)   # fit's record schema
-        if scored:
-            rec["auc"] = meter.value()
-        trainer.history.append(rec)
-        if log:
-            log(_format_record(rec, trainer.step_num - start_step))
+        with spans.span("repro.online.log"):
+            rec = history_record(trainer, loss, t0)   # fit's record schema
+            if scored:
+                rec["auc"] = meter.value()
+            trainer.history.append(rec)
+            if log:
+                log(_format_record(rec, trainer.step_num - start_step))
 
     for _ in range(steps):
         try:
-            b = next(batches)
+            with spans.span("repro.online.next_batch"):
+                b = next(batches)
         except StopIteration:
             break   # finite stream shorter than steps: finish cleanly
         with guard():
@@ -96,7 +99,8 @@ def fit_online(
         if scores is not None:
             # meter update happens OUTSIDE the guard: predict() already
             # materialized scores host-side via an explicit device_get
-            meter.update(b["label"], scores)
+            with spans.span("repro.online.meter"):
+                meter.update(b["label"], scores)
             scored = True
         if trainer.step_num % trainer.cfg.log_every == 0:
             _record()

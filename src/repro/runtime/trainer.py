@@ -64,6 +64,7 @@ from repro.core.kstep import KStepAdam, KStepConfig, pod_replicate, pod_slice
 from repro.core.prefetch import PrefetchingEngine
 from repro.core.sparse_optim import SparseAdagradConfig
 from repro.kernels import ops
+from repro.runtime import spans
 
 Pytree = Any
 
@@ -471,6 +472,9 @@ class HybridTrainer:
         # interleaved serving never moves sparse_metrics (see
         # ``serve_metrics``)
         self._serve_counters: Dict[str, float] = {}
+        # cumulative host->device bytes of the batches ``_stage`` ships
+        # (the host leaves' sizes; nothing on the device is read)
+        self.staged_bytes = 0
         self.history: list = []
 
     def _make_train(self, merge: bool):
@@ -550,6 +554,8 @@ class HybridTrainer:
     def _stage(self, batch):
         # explicit h2d staging: jax.device_put is transfer-guard-exempt
         # (deliberate), where jnp.asarray would count as an implicit sync
+        self.staged_bytes += sum(x.nbytes for x in jax.tree.leaves(batch)
+                                 if isinstance(x, np.ndarray))
         return jax.device_put(batch)
 
     def prefetch(self, batch) -> bool:
@@ -603,22 +609,31 @@ class HybridTrainer:
         is_merge = (self.step_num % self.cfg.kstep.k) == 0
         fn = self._train_merge if is_merge else self._train_local
         if self._prefetcher is not None:
-            if self._prefetcher.pending is None:
-                self.prefetch(batch)   # cold start: pull now (not early)
-            p = self._prefetcher.commit()
+            with spans.span("repro.train.pull"):
+                if self._prefetcher.pending is None:
+                    self.prefetch(batch)   # cold start: pull now (not early)
+                p = self._prefetcher.commit()
             wss, staged = p.wss, p.batch
             tables, accum, bstate = p.tables, p.accum, p.bstate
         else:
-            staged = self._stage(batch)
-            wss, tables, accum, bstate = self.engine.commit(self._pull(
-                self.tables, self.sparse_state.accum, self.backend_state,
-                self.engine.ids_from_batch(staged),
-            ))
-        (self.dense, self.tables, accum, self.backend_state, self.opt_state,
-         loss, self._overflow) = fn(
-            self.dense, tables, accum, bstate, wss,
-            self.pod_batch(staged), self.opt_state, self._overflow,
-        )
+            with spans.span("repro.train.stage"):
+                staged = self._stage(batch)
+            with spans.span("repro.train.ids"):
+                ids = self.engine.ids_from_batch(staged)
+            with spans.span("repro.train.pull"):
+                wss, tables, accum, bstate = self.engine.commit(self._pull(
+                    self.tables, self.sparse_state.accum, self.backend_state,
+                    ids,
+                ))
+        with spans.span("repro.train.pod_batch"):
+            podded = self.pod_batch(staged)
+        with spans.span("repro.train.launch_merge" if is_merge
+                        else "repro.train.launch"):
+            (self.dense, self.tables, accum, self.backend_state,
+             self.opt_state, loss, self._overflow) = fn(
+                self.dense, tables, accum, bstate, wss,
+                podded, self.opt_state, self._overflow,
+            )
         self.sparse_state = self.sparse_state._replace(accum=accum)
         if self.ckpt and self.ckpt.should_save(self.step_num):
             self.save()   # committed state: the next pull is not yet queued
@@ -656,11 +671,13 @@ class HybridTrainer:
         it reads are logically identical to the committed state."""
         if self.engine.store.kind == "disk":
             return self._predict_disk(batch)
-        batch = self._stage(batch)
-        scores, aux = self._predict_jit(
-            self.dense, self.tables, self.sparse_state.accum,
-            self.backend_state, batch,
-        )
+        with spans.span("repro.predict.stage"):
+            batch = self._stage(batch)
+        with spans.span("repro.predict.launch"):
+            scores, aux = self._predict_jit(
+                self.dense, self.tables, self.sparse_state.accum,
+                self.backend_state, batch,
+            )
         return self._finish_predict(scores, aux)
 
     def _predict_disk(self, batch) -> np.ndarray:
@@ -695,7 +712,8 @@ class HybridTrainer:
         # scores are consumed host-side (streaming AUC / response writing):
         # ONE explicit d2h materializes them together with the lookup's
         # serve meters, which accumulate into the serve-side counters
-        got = jax.device_get({"scores": scores, "aux": aux})
+        with spans.span("repro.predict.fetch"):
+            got = jax.device_get({"scores": scores, "aux": aux})
         c = self._serve_counters
         c["serve_requests"] = c.get("serve_requests", 0.0) + float(
             np.asarray(got["scores"]).shape[0])
