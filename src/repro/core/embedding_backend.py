@@ -39,6 +39,8 @@ and whatever physical layout the backend shards by.  Three implementations:
     The single-device / GSPMD path: ``jnp.unique`` dedup + one ``jnp.take``
     gather, push via ``SparseAdagrad.apply_rows``.  Logical layout; under
     GSPMD the gather lowers to masked partials + all-reduce (value-blind).
+    With the Pallas push, a table narrower than 128 lanes is kept packed
+    (``PackedRows``: several logical rows per 128-lane row).
 
 ``RoutedBackend``
     The paper's PS request routing on TPU: tables live hash-sharded
@@ -64,13 +66,18 @@ trainers switch placement with a config flag
 
 from __future__ import annotations
 
+import dataclasses
+import functools
 from typing import NamedTuple, Optional, Protocol, Tuple, runtime_checkable
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
 from repro.core import routed_embedding as routed
 from repro.core.sparse_optim import SparseAdagrad
+from repro.kernels import ops
+from repro.kernels.sparse_adagrad import LANES, adagrad_row_updates
 from repro.launch.mesh import _make_mesh
 
 
@@ -125,6 +132,103 @@ def _dedup(flat_ids: jnp.ndarray, capacity: int):
 
 def _with_drop_row(rows: jnp.ndarray) -> jnp.ndarray:
     return jnp.concatenate([rows, jnp.zeros((1, rows.shape[1]), rows.dtype)])
+
+
+# ------------------------------------------------------------- packed rows
+@functools.partial(jax.tree_util.register_dataclass,
+                   data_fields=["data"], meta_fields=["rows", "dim"])
+@dataclasses.dataclass(frozen=True)
+class PackedRows:
+    """A (rows, dim) table stored ``f = 128 // dim`` logical rows to each
+    128-lane physical row: logical row r is lanes ``(r % f) * dim`` to
+    ``(r % f + 1) * dim`` of ``data[r // f]``.
+
+    A table narrower than a lane tile has no compact row layout on a TPU:
+    XLA keeps (R, 64) transposed, and every row gather, and the Pallas push
+    (which DMAs whole lane tiles), first copies or pads the whole table to
+    128 lanes.  Packed, the table and its accumulator are lane-dense at
+    their logical size, so no step relays them out.  A pytree whose only
+    leaf is ``data``; the logical shape rides in the treedef."""
+
+    data: jnp.ndarray    # (ceil(rows / f), 128)
+    rows: int
+    dim: int
+
+    @property
+    def f(self) -> int:
+        return LANES // self.dim
+
+
+def row_packing(table) -> int:
+    """Logical rows per physical row of a table (1: row layout)."""
+    return table.f if isinstance(table, PackedRows) else 1
+
+
+def _lane_group(wide: jnp.ndarray, lane: jnp.ndarray, f: int, dim: int):
+    """(n, 128) physical rows -> (n, dim): lane group ``lane[i]`` of row i."""
+    out = wide[:, :dim]
+    for j in range(1, f):
+        out = jnp.where((lane == j)[:, None], wide[:, j * dim:(j + 1) * dim],
+                        out)
+    return out
+
+
+def _in_lane_group(x: jnp.ndarray, lane: jnp.ndarray, f: int):
+    """(n, dim) rows -> (n, 128): row i in lane group ``lane[i]``, -0.0 in
+    the others.  -0.0 is the additive identity of IEEE floats (x + -0.0 is
+    x for every x, +0.0 included), so adding such a row changes only the
+    lanes it owns."""
+    return jnp.concatenate(
+        [jnp.where((lane == j)[:, None], x, -0.0) for j in range(f)], axis=1)
+
+
+class PackCounters(NamedTuple):
+    """Cumulative push counters of one packed table (f32, on the device)."""
+
+    merged: jnp.ndarray   # distinct rows folded into an earlier row's write
+    pushed: jnp.ndarray   # distinct rows pushed
+
+
+def _push_packed(table: PackedRows, accum: PackedRows, counters, uids,
+                 grads, opt: SparseAdagrad):
+    """The fused push on packed rows: the unfused scatter's bits, one
+    lane-dense Pallas pass.
+
+    ``adagrad_row_updates`` runs on the logical (cap, dim) rows as on any
+    table.  Each (delta, g2) row goes into its lane group of a 128-lane row,
+    and the rows of one physical row are summed into the first of them.
+    ``uids`` are sorted ascending (pads, which repeat ``uids[0]``, at the
+    end), so those rows are adjacent, at most ``f`` of them.  The others
+    then point at ``uids[0]``'s physical row with a zero update: the only
+    repeats the kernel sees are pads of entry 0, its index contract
+    (``sparse_adagrad_apply_pallas``), so no two of its DMAs race on a row.
+    """
+    f, dim = table.f, table.dim
+    n = uids.shape[0]
+    phys, lane = uids // f, uids % f
+    delta, g2 = adagrad_row_updates(
+        _lane_group(jnp.take(accum.data, phys, axis=0), lane, f, dim),
+        grads, table.data.dtype, lr=opt.cfg.lr, eps=opt.cfg.eps)
+    live = (jnp.arange(n) == 0) | (uids != uids[0])
+    first = live & jnp.concatenate(
+        [jnp.ones((1,), bool), phys[1:] != phys[:-1]])
+    wd, wg = _in_lane_group(delta, lane, f), _in_lane_group(g2, lane, f)
+    fd, fg = wd, wg
+    for j in range(1, f):           # fold entry i + j into entry i
+        same = jnp.concatenate([(phys[j:] == phys[:-j]) & live[j:],
+                                jnp.zeros((j,), bool)])[:, None]
+        tail = jnp.full((j, LANES), -0.0, wd.dtype)
+        fd = fd + jnp.where(same, jnp.concatenate([wd[j:], tail]), -0.0)
+        fg = fg + jnp.where(same, jnp.concatenate([wg[j:], tail]), -0.0)
+    idx = jnp.where(first, phys, phys[0])
+    new_t, new_a = ops.scatter_row_updates(
+        table.data, accum.data, idx,
+        jnp.where(first[:, None], fd, 0.0), jnp.where(first[:, None], fg, 0.0))
+    counters = PackCounters(
+        counters.merged + jnp.sum(live & ~first).astype(jnp.float32),
+        counters.pushed + jnp.sum(live).astype(jnp.float32))
+    return (dataclasses.replace(table, data=new_t),
+            dataclasses.replace(accum, data=new_a), counters)
 
 
 @runtime_checkable
@@ -187,7 +291,13 @@ class GatherBackend:
     kernel (``kernels.ops.sparse_adagrad_apply``): the row update is applied
     straight into the aliased table/accumulator buffers instead of
     materializing the intermediate updated-rows arrays — bit-identical to
-    the unfused scatter (same pinned row math feeds both).
+    the unfused scatter (same pinned row math feeds both).  Where that
+    kernel runs (not ``kernel_mode() == "ref"``) and the width divides 128
+    lanes, ``prepare`` packs the table (``PackedRows``; the accumulator
+    follows its shape), pull and lookup select each row's lane group, and
+    the push folds the rows that share a physical row into one write; the
+    state then counts those folds (``stats``: ``packed_merged`` of
+    ``packed_pushed`` distinct rows).
 
     ``staged=True`` is the DiskStore dataflow (``--store disk``): the
     ``table``/``accum`` the jitted pull/push see are NOT the full table but
@@ -203,13 +313,43 @@ class GatherBackend:
         self.fused = fused
         self.staged = staged
 
-    def init_state(self, table: jnp.ndarray):
+    def _packing(self, dim: int) -> int:
+        """Rows per 128-lane row for a table ``dim`` wide: packed where the
+        push runs through the Pallas kernel and ``dim`` divides 128."""
+        if (self.fused and not self.staged and ops.kernel_mode() != "ref"
+                and dim < LANES and LANES % dim == 0):
+            return LANES // dim
+        return 1
+
+    def init_state(self, table):
+        if isinstance(table, PackedRows):
+            return PackCounters(jnp.zeros((), jnp.float32),
+                                jnp.zeros((), jnp.float32))
         return ()
 
-    def prepare(self, table: jnp.ndarray) -> jnp.ndarray:
-        return table
+    def stats(self, state) -> dict:
+        """A packed table's push counters as python floats (outside jit)."""
+        if not isinstance(state, PackCounters):
+            return {}
+        got = jax.device_get(state)
+        return {"packed_merged": float(got.merged),
+                "packed_pushed": float(got.pushed)}
 
-    def export(self, table: jnp.ndarray) -> jnp.ndarray:
+    def prepare(self, table: jnp.ndarray):
+        rows, dim = table.shape
+        f = self._packing(dim)
+        if f == 1:
+            return table
+        # packed in numpy: on a TPU the (rows, dim) table is laid out
+        # transposed, and reshaping it to 128-lane rows there takes a
+        # temporary twice the table's size; on the host the reshape is free
+        host = np.asarray(jax.device_get(table))
+        data = np.pad(host, ((0, -rows % f), (0, 0))).reshape(-1, LANES)
+        return PackedRows(jax.device_put(data), rows, dim)
+
+    def export(self, table) -> jnp.ndarray:
+        if isinstance(table, PackedRows):
+            return table.data.reshape(-1, table.dim)[: table.rows]
         return table
 
     def flush(self, table, accum, state):
@@ -217,6 +357,10 @@ class GatherBackend:
 
     def _served_rows(self, table, uids, capacity: int) -> jnp.ndarray:
         """(capacity + 1, dim) rows for ``uids`` — shared by pull/lookup."""
+        if isinstance(table, PackedRows):
+            f = table.f
+            wide = jnp.take(table.data, uids // f, axis=0)
+            return _with_drop_row(_lane_group(wide, uids % f, f, table.dim))
         if self.staged:
             if table.shape[0] != capacity:
                 raise ValueError(
@@ -247,6 +391,9 @@ class GatherBackend:
     def push(self, table, accum, state, ws: WorkingSet, row_grads,
              opt: SparseAdagrad):
         # row_grads[capacity] belongs to the drop row — discard it.
+        if isinstance(table, PackedRows):
+            return _push_packed(table, accum, state, ws.uids,
+                                row_grads[: ws.uids.shape[0]], opt)
         if self.staged:
             # elementwise AdaGrad on the staged rows; the updated buffers
             # ride out through the table/accum outputs and the host commits

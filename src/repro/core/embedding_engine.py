@@ -578,8 +578,9 @@ class EmbeddingEngine:
         return new_tables, new_accum, new_states
 
     def cache_counters(self, states) -> Dict[str, float]:
-        """Raw CUMULATIVE cache-tier counters summed across tables ({} for
-        stateless placements).  Call outside jit — materializes the device
+        """Raw CUMULATIVE backend counters summed across tables: the cache
+        tier's, and the packed gather tables' folds ({} for stateless
+        placements).  Call outside jit — materializes the device
         scalars.  Interval (per-logging-window) deltas are the trainer's
         job: it snapshots these totals at each boundary."""
         tot: Dict[str, float] = {}
@@ -603,7 +604,9 @@ class EmbeddingEngine:
         ``1 - 0/max(0, 1)`` would produce in fit history.  Under the
         DiskStore the page-tier meters ride along (``page_hit_rate``,
         ``disk_bytes_read``/``disk_bytes_written``, ``pages_evicted``) —
-        the third level of the hierarchy."""
+        the third level of the hierarchy.  Packed gather tables report
+        ``packed_group_share``: the share of pushed distinct rows whose
+        write was folded into another's (same physical row)."""
         if not counters:
             return {}
         out: Dict[str, float] = {}
@@ -628,6 +631,10 @@ class EmbeddingEngine:
                 "disk_bytes_read": counters["disk_bytes_read"],
                 "disk_bytes_written": counters["disk_bytes_written"],
             })
+        if "packed_pushed" in counters:
+            pushed = counters["packed_pushed"]
+            out["packed_group_share"] = (
+                0.0 if pushed <= 0.0 else counters["packed_merged"] / pushed)
         return out
 
     def cache_stats(self, states) -> Dict[str, float]:

@@ -33,7 +33,6 @@ from repro.kernels.sparse_adagrad import (
     adagrad_row_updates,
     gather_rows_cached_pallas,
     sparse_adagrad_apply_pallas,
-    sparse_adagrad_cached_apply_pallas,
     sparse_adagrad_pallas,
 )
 
@@ -205,6 +204,13 @@ def sparse_adagrad_apply(table, accum, uids, grads, *, lr, eps):
     """
     delta, g2 = adagrad_row_updates(accum[uids], grads, table.dtype,
                                     lr=lr, eps=eps)
+    return scatter_row_updates(table, accum, uids, delta, g2)
+
+
+def scatter_row_updates(table, accum, uids, delta, g2):
+    """``table[uids] += delta``, ``accum[uids] += g2`` in place, under the
+    index contract of ``sparse_adagrad_apply_pallas`` (the only repeats are
+    pads of ``uids[0]`` with a zero update)."""
     mode = kernel_mode()
     if mode == "ref":
         return ref.sparse_adagrad_apply_ref(table, accum, uids, delta, g2)
@@ -242,9 +248,4 @@ def sparse_adagrad_cached_apply(cache_rows, cache_accum, slots, grads,
     accum_rows = gather_rows_cached(cache_accum, slots)
     delta, g2 = adagrad_row_updates(accum_rows, grads, cache_rows.dtype,
                                     lr=lr, eps=eps)
-    mode = kernel_mode()
-    if mode == "ref":
-        return ref.sparse_adagrad_apply_ref(
-            cache_rows, cache_accum, slots, delta, g2)
-    return _run(sparse_adagrad_cached_apply_pallas, mode,
-                cache_rows, cache_accum, slots, delta, g2)
+    return scatter_row_updates(cache_rows, cache_accum, slots, delta, g2)

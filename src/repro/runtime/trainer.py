@@ -59,6 +59,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.checkpoint import CheckpointManager, latest_step, read_manifest
+from repro.core.embedding_backend import row_packing
 from repro.core.embedding_engine import EmbeddingEngine
 from repro.core.kstep import KStepAdam, KStepConfig, pod_replicate, pod_slice
 from repro.core.prefetch import PrefetchingEngine
@@ -751,7 +752,8 @@ class HybridTrainer:
         """Sparse-path health for trainer history/monitoring, PER INTERVAL
         (deltas since the last logging boundary — the current window):
         ``overflow_dropped`` plus, under the cached placement,
-        ``cache_hit_rate``/``evictions``/host<->device byte meters.
+        ``cache_hit_rate``/``evictions``/host<->device byte meters, and
+        for packed gather tables ``packed_group_share``.
         Whole-run cumulative values ride along under ``*_total`` keys
         (``cache_hit_rate_total`` is the whole-run blend).
 
@@ -831,7 +833,9 @@ class HybridTrainer:
         b = self.engine.backend
         sig = {"backend": type(b).__name__,
                "n_shards": getattr(b, "n_shards", 1),
-               "store": self.engine.store.kind}
+               "store": self.engine.store.kind,
+               "row_packing": {n: row_packing(t)
+                               for n, t in self.tables.items()}}
         cache_rows = getattr(b, "cache_rows", None)
         if cache_rows is not None:
             sig["cache_rows"] = int(cache_rows)
@@ -891,8 +895,11 @@ class HybridTrainer:
             sig = self._backend_sig()
             saved = {k: man["meta"][k]
                      for k in ("backend", "n_shards", "cache_rows",
-                               "store", "page_rows")
+                               "store", "page_rows", "row_packing")
                      if k in man["meta"]}
+            # checkpoints older than packing hold row-layout tables
+            saved.setdefault("row_packing",
+                             {n: 1 for n in sig["row_packing"]})
             # pre-store checkpoints carry no "store" key — they were host
             # runs, so only a disk-configured engine must refuse them
             if saved != {k: sig.get(k) for k in saved} or (

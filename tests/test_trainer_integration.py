@@ -154,6 +154,34 @@ def test_resume_rejects_backend_mismatch(tmp_path):
         t_b.resume()
 
 
+def test_resume_rejects_other_row_packing(tmp_path):
+    """A fused gather run keeps its 64-wide table packed two rows to a
+    128-lane row, and checkpoints it so; resuming that into row-layout
+    tables, or a row-layout checkpoint into packed ones, fails on the
+    layout signature instead of on a shape or with wrong rows."""
+    from repro.core.embedding_backend import GatherBackend, PackedRows
+    gen = S.ctr_batches(seed=9, batch=64, rows=CTR_CFG.rows,
+                        n_fields=CTR_CFG.n_fields, nnz=CTR_CFG.nnz_per_instance)
+    batch = next(gen)
+    for saver, resumer in ((True, False), (False, True)):
+        d = str(tmp_path / f"packed_{saver}")
+        t_a = ctr_trainer(n_pod=1, k=1, ckpt_dir=d,
+                          backend=GatherBackend(fused=saver))
+        assert isinstance(t_a.tables["sparse"], PackedRows) == saver
+        t_a.train_step(batch)
+        t_a.save()
+        t_b = ctr_trainer(n_pod=1, k=1, ckpt_dir=d,
+                          backend=GatherBackend(fused=resumer))
+        with pytest.raises(ValueError, match="physical"):
+            t_b.resume()
+        t_c = ctr_trainer(n_pod=1, k=1, ckpt_dir=d,
+                          backend=GatherBackend(fused=saver))
+        assert t_c.resume() and t_c.step_num == 1
+        np.testing.assert_array_equal(
+            np.asarray(t_c.engine.export(t_c.tables)["sparse"]),
+            np.asarray(t_a.engine.export(t_a.tables)["sparse"]))
+
+
 def test_suggest_capacity_from_overflow():
     """Overflow-aware capacity autoscaling (step 1): a trainer that drops
     pulls recommends a larger power-of-two capacity; a clean trainer keeps
