@@ -11,10 +11,13 @@ them) against the float32 reference: the lower readings.  For each
 step below the configuration's matmul precision in the program's place
 (the control, ``refstep.control_numerics``), and the float32 reference
 with half of each pod's shard left out of the loss (a planted fault),
-each against the float32 reference: the upper readings; and for each
-``--faults`` name (``harness.faults.MERGE_FAULTS``) and each
-``--fault-seeds`` seed, the program with that fault planted in its k-step
-merge: its ``merge_gap`` alone.  One JSON line per reading; needs the cell's chips, as a run does.
+each against the float32 reference: the upper readings.  For each
+``--faults`` name and each ``--fault-seeds`` seed, the program with that
+fault planted (``harness.faults.FAULTS``): a merge fault
+(``MERGE_FAULTS``) reads its ``merge_gap`` alone, any other every number
+against the reference, which a ``--seeds`` seed's program run shares.
+One JSON line per reading.  The program's runs need the cell's chips, as a run does;
+the control alone needs one.
 """
 
 import time
@@ -37,7 +40,7 @@ def main(argv=None):
     ap.add_argument("--seeds", type=int, nargs="*", default=[])
     ap.add_argument("--control-seeds", type=int, nargs="*", default=[])
     ap.add_argument("--faults", nargs="*", default=[],
-                    help="merge faults to plant (harness.faults)")
+                    help="faults to plant (harness.faults)")
     ap.add_argument("--fault-seeds", type=int, nargs="*", default=[])
     args = ap.parse_args(argv)
 
@@ -45,7 +48,7 @@ def main(argv=None):
     from run import require_chips
 
     cell = manifest.resolve(manifest.load(), args.workload)
-    require_chips(cell.chips)
+    require_chips(cell.chips if args.seeds or args.faults else 1)
 
     import jax
 
@@ -63,22 +66,23 @@ def main(argv=None):
     def planted(fault):
         def broken(*a, **kw):
             tr = build(*a, **kw)
-            faults.MERGE_FAULTS[fault](tr)
+            faults.FAULTS[fault](tr)
             return tr
         return broken
 
-    for kind, seed in ([("program", s) for s in args.seeds]
-                       + [(f"fault_{f}", s) for f in args.faults
-                          for s in args.fault_seeds]):
-        system.build = (build if kind == "program"
-                        else planted(kind[len("fault_"):]))
+    refs = {}                     # seed -> the reference's readings
+    for fault, seed in ([(None, s) for s in args.seeds]
+                        + [(f, s) for f in args.faults
+                           for s in args.fault_seeds]):
+        kind = "program" if fault is None else f"fault_{fault}"
+        system.build = build if fault is None else planted(fault)
         try:
             run = Run(cell.config, cell.mix, cell.model, seed)
             run.setup()
         finally:
             system.build = build
         run.release()
-        if kind != "program":
+        if fault in faults.MERGE_FAULTS:
             # a merge fault changes the merge alone, and merge_gap needs
             # no reference run: skip the first steps' reference
             ml = check.merge_leaves(run.prog["merge"], cell.config)
@@ -87,9 +91,12 @@ def main(argv=None):
                               "merge_leaves": ml,
                               "setup": run.setup_phases}), flush=True)
             continue
-        ok, nums, _, ref = run.check(limits)
+        t0 = time.perf_counter()
+        ok, nums, _, ref = run.check(limits, refs.get(seed))
+        refs[seed] = ref
         print(json.dumps({"kind": kind, "seed": seed, "correct": ok,
-                          **nums, "losses": run.prog["losses"],
+                          **nums, "check_s": time.perf_counter() - t0,
+                          "losses": run.prog["losses"],
                           "ref_losses": ref["losses"],
                           "readings": check.readings(run.prog, ref,
                                                      cell.config),
@@ -99,7 +106,7 @@ def main(argv=None):
         wseed, dseed = seeds(seed)
         stream = traffic.batches(cell.mix, cfg, dseed)
         first = [next(stream) for _ in range(3)]
-        ref = refstep.run(cell.model, cfg, wseed, first)
+        ref = refs.get(seed) or refstep.run(cell.model, cfg, wseed, first)
         for kind, kw in (("control", {"numerics":
                                       refstep.control_numerics(cfg)}),
                          ("fault_half_batch", {"half_batch": True})):

@@ -1,7 +1,8 @@
-"""Faults planted in the program's k-step merge, for reading the merge
-check's upper end (``calibrate.py``) and for its tests.  Each takes the
-trainer right after it is built, before its merge program is traced, and
-breaks one thing Algorithm 2's merge (Zhao et al., lines 12-13) states.
+"""Faults planted in the program, for reading the check's upper ends
+(``calibrate.py``) and for its tests.  Each takes the trainer right after
+it is built, before its programs are traced.  ``MERGE_FAULTS`` each break
+one thing Algorithm 2's merge (Zhao et al., lines 12-13) states;
+``lost_spill`` loses the cache tier's write-back of evicted rows.
 """
 
 from __future__ import annotations
@@ -43,3 +44,18 @@ def v_hat_kept(tr):
 
 MERGE_FAULTS = {f.__name__: f for f in (no_merge, merge_copies_pod0,
                                          v_hat_unmeaned, v_hat_kept)}
+
+
+def lost_spill(tr):
+    """The cache tier's evicted dirty rows are not written back: the cold
+    tier keeps the value each row had before the cache admitted it."""
+    backend = tr.engine.backend
+    pull = backend.pull
+
+    def unspilled(table, accum, state, flat_ids, capacity):
+        ws, _, _, new_state = pull(table, accum, state, flat_ids, capacity)
+        return ws, table, accum, new_state
+    backend.pull = unspilled
+
+
+FAULTS = {**MERGE_FAULTS, "lost_spill": lost_spill}

@@ -24,8 +24,10 @@ _DONE = object()
 
 class Feed:
     def __init__(self, stream: Iterator[dict], depth: int = 3,
-                 stats: bool = False,
+                 stats=None,
                  span: Optional[Callable[[str], object]] = None):
+        """``stats``: the configuration's reference module, whose tables'
+        distinct ids each batch records in ``distinct``; None for none."""
         self._stream = stream
         self._q: queue.Queue = queue.Queue(maxsize=depth)
         self._stop = threading.Event()
@@ -42,8 +44,8 @@ class Feed:
         try:
             for b in self._stream:
                 with self._span("bench.make_batch"):
-                    item = (b, traffic.distinct_rows(b) if self._stats
-                            else None)
+                    item = (b, None if self._stats is None
+                            else traffic.distinct_rows(self._stats, b))
                 while not self._stop.is_set():
                     try:
                         self._q.put(item, timeout=0.1)

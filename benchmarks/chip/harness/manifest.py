@@ -6,7 +6,8 @@ per-layer metric takes new files and a new entry only:
 
   configuration  the ``file`` its entry names (sizes, deployment, limits),
                  with its plain reference ``reference/<reference>.py``
-  traffic mix    ``traffic/<traffic>.json``
+  traffic mix    ``traffic/<traffic>.json``, generated by the module of
+                 its ``kind``, ``traffic/<kind>.py``
   per-layer      ``metrics/<name>.py``, a ``read(ctx)`` that returns the
   metric         reading or None where it finds nothing to read
 """
@@ -14,13 +15,12 @@ per-layer metric takes new files and a new entry only:
 from __future__ import annotations
 
 import dataclasses
-import importlib.util
 import json
 import re
 from pathlib import Path
 from typing import Dict, List
 
-from harness import BENCH_DIR, REPO_ROOT, traffic
+from harness import BENCH_DIR, REPO_ROOT, load_module, traffic
 
 NAME = re.compile(r"^[A-Za-z0-9_][A-Za-z0-9_.-]{0,63}$")
 UNIT = re.compile(r"^[A-Za-z0-9_/%.-]{1,16}$")
@@ -123,6 +123,11 @@ def validate(m: dict, root: Path = REPO_ROOT) -> List[str]:
                 w["traffic"], root).is_file():
             errs.append(f"cell {w['name']}: no traffic file for "
                         f"{w['traffic']}")
+        else:
+            try:
+                traffic.load(traffic_file(w["traffic"], root))
+            except ValueError as e:
+                errs.append(f"cell {w['name']}: {e}")
         if (w["config"], w["traffic"]) in pairs:
             errs.append(f"cell {w['name']}: configuration and traffic "
                         f"pair appears twice")
@@ -201,13 +206,6 @@ def metric_file(name: str, root: Path = REPO_ROOT) -> Path:
 def reference_file(name: str, root: Path = REPO_ROOT) -> Path:
     return Path(root) / BENCH_DIR.relative_to(REPO_ROOT) / "reference" / (
         name + ".py")
-
-
-def load_module(path: Path, name: str):
-    spec = importlib.util.spec_from_file_location(name, path)
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    return mod
 
 
 @dataclasses.dataclass

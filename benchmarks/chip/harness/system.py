@@ -5,14 +5,15 @@ The only module of the benchmark that imports the program.  It builds the
 the launcher's own flags (``repro.launch.train.build_argparser`` /
 ``trainer_config``), as ``python -m repro.launch.train`` does, and reads
 what the check needs through the trainer's public state and the engine's
-read-only lookup.
+read-only lookup.  After the first step it fills a cache tier through the
+engine's own pull stage.
 """
 
 from __future__ import annotations
 
 import dataclasses
 import math
-from typing import Dict
+from typing import Dict, Iterator
 
 import jax
 import jax.numpy as jnp
@@ -77,6 +78,51 @@ def build(cfg: dict, batch: int, seed: int):
     _check_stated(cfg, tcfg)
     return build_trainer(cfg["arch"], tcfg, model_cfg=model_config(cfg),
                          seed=seed, table_scale=cfg["table_init_std"])
+
+
+def fill_cache(trainer, batches: Iterator[dict]) -> int:
+    """Pull ``batches`` through the trainer's own compiled pull stage,
+    training on none of them, until the cache tier has admitted a row into
+    each of its slots, and on until its evictions have written rows back
+    to the cold tier and a pull writes back none more.  The cache is then
+    full, as a deployment's is, and every later miss evicts.  Called after
+    a step, the pulls evict the rows that step trained and left dirty (the
+    oldest and coldest), so what is read back from them goes through the
+    write-back.  A pull changes no row's value, so every later reading is
+    the one the trainer gives with no fill.  Stops early where a pull
+    admits nothing, and after ``8 * ceil(slots / capacity)`` pulls at the
+    most.  Returns the pulls made; 0 where the placement has no cache."""
+    engine = trainer.engine
+    slots = int(getattr(engine.backend, "cache_rows", 0) or 0)
+    if not slots:
+        return 0
+    target = sum(min(slots, int(t.shape[0]))
+                 for t in trainer.tables.values())
+    most = 8 * -(-slots // int(engine.capacity))
+    pull = engine.pull_stage(donate=trainer.cfg.donate)
+    last = counters(trainer)
+    spilled = False
+    pulls = 0
+    batch = next(batches)
+    while pulls < most:
+        ids = engine.ids_from_batch(jax.device_put(batch))
+        # the pull donates the sparse state; its outputs replace it, as
+        # ``HybridTrainer.prefetch`` replaces it after a dispatch
+        _, trainer.tables, accum, trainer.backend_state = pull(
+            trainer.tables, trainer.sparse_state.accum,
+            trainer.backend_state, ids)
+        trainer.sparse_state = trainer.sparse_state._replace(accum=accum)
+        pulls += 1
+        batch = next(batches)          # made while the pull runs
+        now = counters(trainer)
+        if now["cache_fetched"] == last["cache_fetched"]:
+            break
+        wrote = now["cache_bytes_d2h"] > last["cache_bytes_d2h"]
+        if now["cache_fetched"] >= target and spilled and not wrote:
+            break
+        spilled = spilled or wrote
+        last = now
+    return pulls
 
 
 def read_rows(trainer, uids: Dict[str, np.ndarray]) -> Dict[str, np.ndarray]:

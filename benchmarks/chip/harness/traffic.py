@@ -1,12 +1,15 @@
-"""The one traffic generator.  A traffic mix is a JSON file of parameters
-under ``benchmarks/chip/traffic/<name>.json``; the model's sizes come from
-the configuration.
+"""The traffic generator.  A traffic mix is a JSON file of parameters
+under ``benchmarks/chip/traffic/<name>.json``; its ``kind`` names the
+module beside it, ``traffic/<kind>.py``, whose ``batches(rng, mix, cfg)``
+yields the kind's batches.  The model's sizes come from the
+configuration, so a model with a batch layout of its own brings its kind
+as a new module and its mixes as new files.
 
-The generators are copies of ``repro.data.synthetic.ctr_batches`` and
-``dlrm_batches`` (labels from a hidden teacher, so AUC means something),
-with the Zipf exponent and the mask rate read from the mix instead of
-fixed in code.  They are copied so that no change to the program can
-change the yardstick.
+This module keeps what the kinds share: the Zipf-like id draw and the
+teacher's per-id weights, copied from ``repro.data.synthetic`` so that no
+change to the program can change the yardstick.  Which ids of a batch
+belong to which table is the configuration's reference's to say
+(``reference/<name>.py`` ``table_ids``).
 """
 
 from __future__ import annotations
@@ -17,20 +20,27 @@ from typing import Dict, Iterator
 
 import numpy as np
 
-KINDS = ("ctr", "dlrm")
+from harness import load_module
+
+
+def kind_file(mix_path: Path, kind: str) -> Path:
+    return Path(mix_path).parent / f"{kind}.py"
 
 
 def load(path: Path) -> dict:
+    """The mix at ``path``, with ``kind_file``: the module of its kind."""
     mix = json.loads(Path(path).read_text())
-    if mix.get("kind") not in KINDS:
-        raise ValueError(f"{path}: kind must be one of {KINDS}, got "
-                         f"{mix.get('kind')!r}")
+    kind = mix.get("kind")
+    if not isinstance(kind, str) or not kind.isidentifier() \
+            or not kind_file(path, kind).is_file():
+        raise ValueError(f"{path}: kind {kind!r} has no generator "
+                         f"{kind_file(path, str(kind))}")
     if int(mix.get("batch", 0)) <= 0:
         raise ValueError(f"{path}: batch must be a positive integer")
-    return mix
+    return dict(mix, kind_file=str(kind_file(path, kind)))
 
 
-def _id_weights(ids: np.ndarray, salt: int = 0x9E3779B9) -> np.ndarray:
+def id_weights(ids: np.ndarray, salt: int = 0x9E3779B9) -> np.ndarray:
     """Deterministic pseudo-random weight per id in [-1, 1] (splitmix)."""
     x = (ids.astype(np.uint64) + np.uint64(salt)) * np.uint64(
         0xBF58476D1CE4E5B9)
@@ -48,59 +58,16 @@ def zipf_ids(rng: np.random.Generator, shape, vocab: int,
     return np.minimum(ids.astype(np.int64), vocab - 1)
 
 
-def ctr_batches(rng, batch: int, rows: int, n_fields: int, nnz: int,
-                zipf_a: float, keep: float) -> Iterator[Dict[str, np.ndarray]]:
-    while True:
-        ids = zipf_ids(rng, (batch, nnz), rows, zipf_a)
-        field_ids = rng.integers(0, n_fields, (batch, nnz)).astype(np.int32)
-        mask = (rng.random((batch, nnz)) < keep).astype(np.float32)
-        score = (_id_weights(ids) * mask).sum(1) / np.sqrt(nnz)
-        pair = _id_weights(ids, salt=17) * mask
-        score = score + 0.5 * (pair.sum(1) ** 2 - (pair ** 2).sum(1)) / nnz
-        p = 1.0 / (1.0 + np.exp(-3.0 * score))
-        label = (rng.random(batch) < p).astype(np.float32)
-        yield {"ids": ids.astype(np.int32), "field_ids": field_ids,
-               "mask": mask, "label": label}
+def batches(mix: dict, cfg: dict, seed: int) -> Iterator[dict]:
+    """The mix's endless batch stream for the configuration ``cfg``,
+    fixed by ``seed``."""
+    kind = load_module(Path(mix["kind_file"]),
+                       f"bench_traffic_{mix['kind']}")
+    return kind.batches(np.random.default_rng(seed), mix, cfg)
 
 
-def dlrm_batches(rng, batch: int, rows, n_dense: int,
-                 zipf_a: float) -> Iterator[Dict[str, np.ndarray]]:
-    rows = list(rows)
-    while True:
-        dense = rng.standard_normal((batch, n_dense)).astype(np.float32)
-        ids = np.stack([zipf_ids(rng, (batch,), r, zipf_a) for r in rows],
-                       axis=1)
-        w = np.stack([_id_weights(ids[:, i], salt=31 * i + 7)
-                      for i in range(len(rows))], 1)
-        score = (w.mean(1) * 2.0 + 0.3 * dense[:, :4].sum(1) / 2.0
-                 + 0.4 * w[:, 0] * w[:, 1])
-        p = 1.0 / (1.0 + np.exp(-2.0 * score))
-        label = (rng.random(batch) < p).astype(np.float32)
-        yield {"dense": dense, "sparse_ids": ids.astype(np.int32),
-               "label": label}
-
-
-def batches(mix: dict, model: dict, seed: int) -> Iterator[dict]:
-    """The mix's endless batch stream for ``model``, fixed by ``seed``."""
-    rng = np.random.default_rng(seed)
-    if mix["kind"] == "ctr":
-        return ctr_batches(rng, int(mix["batch"]), int(model["rows"]),
-                           int(model["n_fields"]),
-                           int(model["nnz_per_instance"]),
-                           float(mix["zipf_a"]), float(mix["keep"]))
-    return dlrm_batches(rng, int(mix["batch"]), model["rows"],
-                        int(model["n_dense"]), float(mix["zipf_a"]))
-
-
-def table_ids(batch: dict) -> Dict[str, np.ndarray]:
-    """Each table's ids in ``batch``, (instances, ids per instance): the
-    one table ``sparse`` of the CTR model, or DLRM's 26 one-hot tables."""
-    if "ids" in batch:
-        return {"sparse": batch["ids"]}
-    ids = batch["sparse_ids"]
-    return {f"emb_{i:02d}": ids[:, i:i + 1] for i in range(ids.shape[1])}
-
-
-def distinct_rows(batch: dict) -> Dict[str, int]:
-    """Distinct ids per table in ``batch``: the rows a pull must move."""
-    return {n: int(np.unique(x).size) for n, x in table_ids(batch).items()}
+def distinct_rows(model, batch: dict) -> Dict[str, int]:
+    """Distinct ids per table in ``batch``, the tables as the reference
+    ``model`` reads them: the rows a pull must move."""
+    return {n: int(np.unique(x).size)
+            for n, x in model.table_ids(batch).items()}

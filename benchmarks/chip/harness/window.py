@@ -7,10 +7,16 @@ Its first steps go through the window's own call (``fit_online``) and
 feed, on the mix's first batches (all distinct); the program's readings
 for the check are taken between them: the rows those batches touch before
 step 1, after step 1 and after step ``REF_STEPS``, the dense tower and
-Adam's first moment, the losses and the served scores; then the dense tower right before the first k-step merge, and the
-tower and the moments right after it.  Warm-up runs past that merge,
-so every program the window runs has compiled before it opens.  The
-program runs at the matmul precision its configuration states
+Adam's first moment, the losses and the served scores; then the dense
+tower right before the first k-step merge, and the tower and the moments
+right after it.  Where the placement has a cache tier, set-up fills it
+right after step 1 with pulls of the mix's traffic drawn from a seed of
+its own (``system.fill_cache``), which change no value: the cache is full
+from step 2 on, and the fill's last pulls evict rows that step 1 trained,
+so the readings after step 1 and the steps after it read those rows back
+through the cache tier's write-back.  Warm-up runs past the merge, so
+every program the window runs has compiled before it opens.  The program
+runs at the matmul precision its configuration states
 (``matmul_precision``; JAX's ``jax_default_matmul_precision``).
 """
 
@@ -81,8 +87,16 @@ def seeds(seed: int):
     return int(w) & 0x7FFFFFFF, int(d)
 
 
-def ids_per_batch(batch: dict) -> int:
-    return sum(int(x.size) for x in traffic.table_ids(batch).values())
+def fill_seed(seed: int) -> int:
+    """The seed of the stream that fills a cache tier after the first
+    step: the same traffic as the run's, drawn apart from it."""
+    child = np.random.SeedSequence(int(seed)).spawn(1)[0]
+    return int(child.generate_state(1)[0])
+
+
+def ids_per_batch(model, batch: dict) -> int:
+    """Ids in ``batch`` over every table the reference ``model`` reads."""
+    return sum(int(x.size) for x in model.table_ids(batch).values())
 
 
 class Run:
@@ -97,6 +111,7 @@ class Run:
         self._fit = fit_online
         self.t_process = (time.perf_counter() if t_process is None
                           else t_process)
+        self.seed = seed
         self.wseed, self.dseed = seeds(seed)
         self.span = ((lambda n: jax.profiler.TraceAnnotation(n)) if trace
                      else (lambda n: contextlib.nullcontext()))
@@ -124,7 +139,8 @@ class Run:
         jax.block_until_ready(tr.tables)
         self._phase("build")
         self.feed = Feed(traffic.batches(self.mix, self.cfg, self.dseed),
-                         stats=self.trace, span=self.span)
+                         stats=self.model if self.trace else None,
+                         span=self.span)
         self.inst = inst = Instrumented(tr, self.span)
         self.first = self.feed.take(REF_STEPS)
         uids = refstep.touched(self.model, self.first)
@@ -135,6 +151,14 @@ class Run:
         inst.keep_scores = REF_STEPS
         self.steps(self.first[:1])
         self._phase("step1")
+        before = system.counters(tr)
+        self.fill_pulls = system.fill_cache(
+            tr, traffic.batches(self.mix, self.cfg, fill_seed(self.seed)))
+        jax.block_until_ready(tr.tables)
+        self.fill_spilled_bytes = (
+            system.counters(tr).get("cache_bytes_d2h", 0.0)
+            - before.get("cache_bytes_d2h", 0.0))
+        self._phase("fill")
         p["rows1"] = system.read_rows(tr, uids)
         p["moment1"] = system.first_moment(tr)
         self.steps(self.first[1:])
@@ -188,7 +212,7 @@ class Run:
         losses = np.asarray(jax.device_get(inst.losses[n_loss0:]))
         k = int(self.cfg["deployment"]["k"])
         step_nums = step0 + 1 + np.arange(n)
-        ids = ids_per_batch(self.first[0])
+        ids = ids_per_batch(self.model, self.first[0])
         self.result = {
             "steps": n,
             "instances": n * int(self.mix["batch"]),
@@ -230,9 +254,11 @@ class Run:
         gc.collect()
 
     # ------------------------------------------------------------- check
-    def check(self, limits: dict):
-        """Compare the program's readings with the float32 reference's."""
-        ref = refstep.run(self.model, self.cfg, self.wseed, self.first)
+    def check(self, limits: dict, ref: Optional[dict] = None):
+        """Compare the program's readings with the float32 reference's
+        (``ref``: the reference's readings for this seed, if made)."""
+        if ref is None:
+            ref = refstep.run(self.model, self.cfg, self.wseed, self.first)
         prog = dict(self.prog)
         prog["window_compiles"] = len(self.result["window_compiles"]) \
             if hasattr(self, "result") else 0

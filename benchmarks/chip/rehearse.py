@@ -14,6 +14,7 @@ asks for, not how fast it is.
 
 import argparse
 import copy
+import functools
 import json
 import os
 import sys
@@ -21,6 +22,26 @@ import sys
 HERE = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, HERE)
 sys.path.insert(0, os.path.join(HERE, "..", "..", "src"))
+
+
+def abstract(tree, full_rows=None, sharding=None):
+    """``tree`` as shapes on ``sharding``; a table named in ``full_rows``
+    (name -> logical rows) gets the physical rows of that many logical
+    ones, ``ceil(rows / row_packing(table))`` where its rows are packed."""
+    import jax
+
+    from repro.core.embedding_backend import row_packing
+
+    full_rows = full_rows or {}
+
+    def one(path, x):
+        shape = x.shape
+        name = jax.tree_util.keystr(path[:1]).strip("[]'")
+        if name in full_rows:
+            f = row_packing(tree[name])
+            shape = (-(-full_rows[name] // f),) + shape[1:]
+        return jax.ShapeDtypeStruct(shape, x.dtype, sharding=sharding)
+    return jax.tree_util.tree_map_with_path(one, tree)
 
 
 def main(argv=None):
@@ -33,7 +54,7 @@ def main(argv=None):
     from jax.experimental import topologies
     from jax.sharding import SingleDeviceSharding
 
-    from harness import manifest, system
+    from harness import manifest, system, traffic
     from repro.kernels import ops
 
     jax.config.update("jax_enable_compilation_cache", False)
@@ -50,24 +71,13 @@ def main(argv=None):
     chip = SingleDeviceSharding(topologies.get_topology_desc(
         platform="tpu", topology_name="v5e:2x2").devices[0])
     tr = system.build(small, int(cell.mix["batch"]), 0)
-    from harness import traffic
-
     batch = next(traffic.batches(cell.mix, small, 0))
 
     full_rows = dict(zip(sorted(tr.tables), rows if isinstance(rows, list)
                          else [rows]))
-
-    def sds(tree, table_rows=False):
-        def one(path, x):
-            shape = x.shape
-            name = jax.tree_util.keystr(path[:1]).strip("[]'")
-            if table_rows and name in full_rows:
-                shape = (full_rows[name],) + shape[1:]
-            return jax.ShapeDtypeStruct(shape, x.dtype, sharding=chip)
-        return jax.tree_util.tree_map_with_path(one, tree)
-
-    tables = sds(tr.tables, table_rows=True)
-    accum = sds(tr.sparse_state.accum, table_rows=True)
+    sds = functools.partial(abstract, sharding=chip)
+    tables = sds(tr.tables, full_rows)
+    accum = sds(tr.sparse_state.accum, full_rows)
     bstate, dense = sds(tr.backend_state), sds(tr.dense)
     opt, over = sds(tr.opt_state), sds(tr._overflow)
     staged = sds(jax.device_put(batch))

@@ -105,6 +105,8 @@ def main(argv=None):
 
     iv = res["intervals_ms"]
     merge = iv[res["merge_step"]]
+    delta = {k: v - run.counters0.get(k, 0.0)
+             for k, v in run.counters1.items()}
     slow = [{"step": int(res["step_nums"][i]), "ms": float(iv[i]),
              "feed_wait_ms": float(res["feed_wait_ms"][i])}
             for i in np.argsort(iv)[::-1][:3]]
@@ -114,7 +116,10 @@ def main(argv=None):
           f"{float(np.median(merge)) if len(merge) else float('nan')!r}; "
           f"memory {json.dumps(mem)}; compiles in window "
           f"{res['window_compiles']}; set-up s {json.dumps(run.setup_phases)}"
-          f"; slowest steps {json.dumps(slow)}", file=sys.stderr)
+          f"; slowest steps {json.dumps(slow)}; cache filled by "
+          f"{run.fill_pulls} pulls, which wrote back "
+          f"{run.fill_spilled_bytes!r} bytes; window counters {json.dumps(delta)}",
+          file=sys.stderr)
     if args.trace:
         metrics = {}
         for x in cell.per_layer:

@@ -65,3 +65,21 @@ def test_published_widths_ctr_is_about_17_mflop_per_instance():
                     "baidu-ctr-1of512.json").read_text())
     per = counts.step_flops_per_instance(_model("ctr"), c)
     assert 16e6 < per < 18e6
+
+
+def test_rehearsal_gives_a_packed_table_its_physical_rows():
+    """``rehearse.py`` sizes each table at its full logical rows, stored as
+    the program stores them: baidu-ctr's 64-wide table two rows to a
+    128-lane row, DLRM's 128-wide tables one."""
+    import jax.numpy as jnp
+
+    import rehearse
+    from repro.core.embedding_backend import PackedRows
+
+    small = {"sparse": PackedRows(jnp.zeros((5, 128)), 9, 64),
+             "emb_00": jnp.zeros((9, 128))}
+    got = rehearse.abstract(small, {"sparse": 3906249, "emb_00": 40000000})
+    assert got["sparse"].data.shape == (1953125, 128)
+    assert got["sparse"].rows == 9          # static metadata, not a shape
+    assert got["emb_00"].shape == (40000000, 128)
+    assert rehearse.abstract(small)["emb_00"].shape == (9, 128)

@@ -114,3 +114,32 @@ def test_recorded_chip_trace():
         red.window_s * 1e3)
     labels = [k for k, _ in tracing.breakdown(red)["idle_gaps"]]
     assert labels and all(k.startswith("host:") for k in labels)
+
+
+def _reader(name):
+    from harness import manifest
+
+    return manifest.load_module(manifest.metric_file(name), f"test_{name}")
+
+
+def test_cache_hit_share_reads_the_window_s_counters():
+    import types
+
+    ctx = types.SimpleNamespace(
+        counters0={"cache_lookups": 1000.0, "cache_fetched": 400.0},
+        counters1={"cache_lookups": 3000.0, "cache_fetched": 700.0})
+    # 2000 lookups in the window, 300 of them fetched from the cold tier
+    assert _reader("cache_hit_share").read(ctx) == pytest.approx(85.0)
+    ctx.counters0 = ctx.counters1 = {"overflow_dropped": 0.0}  # no cache
+    assert _reader("cache_hit_share").read(ctx) is None
+
+
+def test_cache_evictions_per_step_reads_the_window_s_counters():
+    import types
+
+    ctx = types.SimpleNamespace(
+        steps=4, counters0={"cache_evictions": 500.0},
+        counters1={"cache_evictions": 4500.0})
+    assert _reader("cache_evictions_per_step").read(ctx) == 1000.0
+    ctx.counters0 = ctx.counters1 = {"overflow_dropped": 0.0}  # no cache
+    assert _reader("cache_evictions_per_step").read(ctx) is None
